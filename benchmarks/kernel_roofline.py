@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import DistConfig, DistributedNystrom, KernelSpec, TronConfig
-from repro.core import compat
 from repro.core.compat import make_mesh
 from repro.core.tron import tron
 
@@ -110,7 +109,7 @@ def main():
                 n, m, d, mode, mat, mesh,
                 c_dtype=jnp.bfloat16 if plan == "bf16C" else jnp.float32)
             compiled = lowered.compile()
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             colls = _coll_bytes(compiled.as_text())
             flops = float(cost.get("flops", 0))
             byts = float(cost.get("bytes accessed", 0))

@@ -20,6 +20,10 @@ gap IS the deployment price the paper's Table 4 slices, measured here.
 
 Emits the repo-root ``BENCH_multihost.json`` trajectory record.
 
+All fleet processes run on this one host, so this is a CPU simulation
+only (``JAX_PLATFORMS=cpu``, fake devices): on a TPU host a chip belongs
+to one process, and the times here are CPU times, not device metrics.
+
 Run:  PYTHONPATH=src python -m benchmarks.multihost_scaling [--smoke]
 
 (The module re-invokes itself with ``--worker`` for each fleet process;

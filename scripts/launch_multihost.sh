@@ -2,7 +2,8 @@
 # Local multi-controller launcher: run N copies of a repro.launch CLI as
 # N simulated hosts (one process per "host", K fake CPU devices each via
 # --xla_force_host_platform_device_count), wired together through a
-# jax.distributed coordinator on localhost.
+# jax.distributed coordinator on localhost. A CPU simulation only: on a
+# TPU host a chip belongs to one process, and one process drives them all.
 #
 #   scripts/launch_multihost.sh [-n NPROC] [-d DEV_PER_PROC] [-p PORT] \
 #       [-m MODULE] [-l LOGDIR] -- <args passed to every process>

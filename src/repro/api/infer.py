@@ -120,11 +120,24 @@ def decide_local(config, mesh, spec: DecisionSpec, X, *,
     del mesh
     Xe = spec.map_x(jnp.asarray(X))
     if spec.identity_basis:
-        return Xe @ spec.beta
+        return _row_contract(Xe, spec.beta)
     C = gram(Xe, spec.basis, spec.kernel,
              backend if backend is not None else spec.backend,
              policy=spec.policy if spec.policy != "fp32" else None)
-    return C @ spec.beta
+    return _row_contract(C, spec.beta)
+
+
+def _row_contract(A, beta):
+    """A·β for A (n, m) and β (m,) or (m, K), as a multiply-and-reduce.
+
+    Not a matmul: XLA:CPU's GEMM/GEMV tiling rounds a row differently
+    depending on its position in the batch, so a coalesced serving batch
+    would not be bitwise the same rows decided alone. The reduce sums each
+    row's m products in one fixed order. On a TPU the f32 products and sums
+    run on the vector unit, so no DEFAULT-precision bf16 MXU pass either."""
+    beta = jnp.asarray(beta)
+    prod = A[..., None] * beta if beta.ndim == 2 else A * beta
+    return jnp.sum(prod, axis=1)
 
 
 # ------------------------------------------------------- fused (on-mesh)
@@ -155,9 +168,12 @@ def make_margin_body(config, mesh, spec: DecisionSpec,
     map_x = spec.map_x
 
     if spec.identity_basis:
+        from repro.kernels.policy import get_policy
+        precision = get_policy(spec.policy).precision
+
         def o_local(Xl, basis, beta):
             del basis                      # o = φ(x)·β exactly, no gram
-            return map_x(Xl) @ beta
+            return jnp.matmul(map_x(Xl), beta, precision=precision)
     else:
         def o_local(Xl, basis, beta):
             return otf_kmvp_fwd(map_x(Xl), basis, beta, **kw)

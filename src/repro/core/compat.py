@@ -1,78 +1,26 @@
-"""Version-compat wrappers for the small set of jax APIs that moved.
+"""The mesh and shard_map spellings every call site shares.
 
-The repo targets current jax (``jax.shard_map``, ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``); the container may ship an older
-release where shard_map still lives in ``jax.experimental`` under the
-``check_rep`` spelling and ``make_mesh`` has no ``axis_types``. Every
-internal call site goes through this module so the difference is absorbed
-in exactly one place.
+``shard_map`` is ``jax.shard_map`` (call sites pass ``check_vma``).
+``make_mesh`` pins every axis to ``AxisType.Auto``: the sharded closures
+are written for sharding resolved by the compiler, and ``jax.make_mesh``
+would otherwise make the axes Explicit.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence, Tuple
 
 import jax
 
-try:  # jax >= 0.5: top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SMAP_PARAMS = set(inspect.signature(_shard_map_impl).parameters)
-# replication/varying-manual-axes check kwarg was renamed check_rep -> check_vma
-_CHECK_KW = "check_vma" if "check_vma" in _SMAP_PARAMS else "check_rep"
-
-
-def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the modern keyword spelling on any jax."""
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs,
-              _CHECK_KW: check_vma}
-    if f is None:  # support partial-style usage: shard_map(mesh=...)(f)
-        return lambda fn: _shard_map_impl(fn, **kwargs)
-    return _shard_map_impl(f, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any jax version
-    (older releases return a one-element list of per-program dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
-def axis_size(name) -> int:
-    """``jax.lax.axis_size`` (newer jax) with a psum(1) fallback that is
-    constant-folded to the same static extent inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-_MESH_PARAMS = set(inspect.signature(jax.make_mesh).parameters)
-_HAS_AXIS_TYPES = "axis_types" in _MESH_PARAMS
-
-
-def default_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where supported, else None."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return None
-    return (at.Auto,) * n
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              devices=None, axis_types=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` accepting (and dropping, if unsupported) axis_types."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = default_axis_types(len(tuple(axis_shapes)))
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    shape = tuple(axis_shapes)
+    return jax.make_mesh(shape, tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def default_mesh(data_axes: Tuple[str, ...] = ("data",),

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import axis_size, shard_map
+from repro.core.compat import shard_map
 # the rank-generic reductions (_colsum, and _ct_v with its XLA-CPU
 # transpose-avoidance NOTE) are shared with the local-math module
 from repro.core.formulation import _colsum, _ct_v
@@ -109,7 +109,7 @@ def _dp_index(data_axes):
     """Linearized index of this device along the (possibly nested) data axes."""
     idx = jax.lax.axis_index(data_axes[0])
     for ax in data_axes[1:]:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -365,18 +365,18 @@ class DistributedNystrom:
                     w=ns(self.w_spec), rep=ns(self.rep_spec))
 
     def precompute(self, X, basis):
-        """Steps 2-3: broadcast basis, build sharded C and W."""
-        sh = self.shardings()
-        kern, backend = self.kernel, self.dist.backend
-        pol = self.dist._gram_policy()
+        """Steps 2-3: broadcast basis, build sharded C and W.
 
-        @partial(jax.jit, out_shardings=(sh["c"], sh["w"]))
-        def _build(X, basis):
-            C = gram(X, basis, kern, backend, policy=pol)
-            W = gram(basis, basis, kern, backend, policy=pol)
-            return C, W
-
-        return _build(X, basis)
+        Each device builds only its own (C, W) blocks inside shard_map: the
+        compiler cannot partition a Pallas gram, and would otherwise gather
+        X onto every device and build all of C there."""
+        m = basis.shape[0]
+        build = shard_map(lambda Xl, b: self._otf_blocks(Xl, b, m),
+                          mesh=self.mesh, check_vma=False,
+                          in_specs=(self.x_spec, self.rep_spec),
+                          out_specs=(self.c_spec, self.w_spec))
+        with self.mesh:
+            return jax.jit(build)(X, basis)
 
     # -------------------------------------------------------------- closures
     def _local_fgrad(self, Cb, Wb, yb, beta):
@@ -455,12 +455,12 @@ class DistributedNystrom:
         da, ma = self.dist.data_axes, self.dist.model_axis
         dp_total = 1
         for ax in da:
-            dp_total *= axis_size(ax)
+            dp_total *= jax.lax.axis_size(ax)
         m_dp = m // dp_total
         row0 = _dp_index(da) * m_dp
         basis_rows = jax.lax.dynamic_slice_in_dim(basis, row0, m_dp, 0)
         if ma is not None:
-            m_mp = m // axis_size(ma)
+            m_mp = m // jax.lax.axis_size(ma)
             col0 = jax.lax.axis_index(ma) * m_mp
             basis_cols = jax.lax.dynamic_slice_in_dim(basis, col0, m_mp, 0)
         else:
@@ -551,7 +551,7 @@ class DistributedNystrom:
             """(row0, basis row-block) this device owns for W contractions."""
             dp_total = 1
             for ax in da:
-                dp_total *= axis_size(ax)
+                dp_total *= jax.lax.axis_size(ax)
             m_dp = m // dp_total
             row0 = _dp_index(da) * m_dp
             return row0, m_dp, jax.lax.dynamic_slice_in_dim(
@@ -858,7 +858,6 @@ class DistributedNystrom:
             basis = self._as_replicated(basis)
             if beta0 is not None:
                 beta0 = self._as_replicated(beta0)
-        if multihost.active():
             if not self.dist.fused or self.dist.materialize:
                 raise ValueError(
                     "multi-controller in-memory fits route through the "
@@ -875,37 +874,31 @@ class DistributedNystrom:
                 beta0 = self._as_replicated(
                     np.zeros((basis.shape[0],), np.dtype(X.dtype)))
 
-            # non-addressable arrays may not be *closed over* inside jit —
-            # build the closures on the traced arguments instead
-            @jax.jit
-            def _run_global(X, y, basis, beta0):
-                fgrad, hessd = self.make_fused_closures(X, y, basis)
-                return tron(fgrad, hessd, beta0, cfg)
-
-            with self.mesh:
-                return _run_global(X, y, basis, beta0)
-
         if self.dist.materialize:
             C, W = self.precompute(X, basis)
-            fgrad, hessd = self.make_closures(C, W, y)
+            make, data = self.make_closures, (C, W, y)
         elif self.dist.fused:
-            fgrad, hessd = self.make_fused_closures(X, y, basis)
+            make, data = self.make_fused_closures, (X, y, basis)
         else:
-            fgrad, hessd = self.make_otf_closures(X, y, basis)
+            make, data = self.make_otf_closures, (X, y, basis)
         if beta0 is None:
             beta0 = jnp.zeros((basis.shape[0],), X.dtype)
 
         if checkpoint is None and state0 is None:
+            # the data are arguments, not closure constants: a closed-over
+            # array would be baked into the program (X twice on the device,
+            # a compile-cache key per dataset), and a process-spanning one
+            # may not be closed over at all
             @jax.jit
-            def _run(beta0):
-                return tron(fgrad, hessd, beta0, cfg)
+            def _run(data, beta0):
+                return tron(*make(*data), beta0, cfg)
 
             with self.mesh:
-                return _run(beta0)
+                return _run(data, beta0)
         # checkpointed/resumed: tron segments its own jitted while_loop so
         # the host can snapshot between segments (no outer jit here)
         with self.mesh:
             return tron(
-                fgrad, hessd, beta0, cfg, state0=state0,
+                *make(*data), beta0, cfg, state0=state0,
                 snapshot_every=checkpoint.interval if checkpoint else 0,
                 on_snapshot=checkpoint.on_snapshot if checkpoint else None)

@@ -10,7 +10,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
+
+# fp32 gram cross terms: at DEFAULT a TPU runs an f32 dot as one bf16 MXU
+# pass, and the ||x||^2 + ||z||^2 - 2 x.z cancellation amplifies that error
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,7 +34,7 @@ def sqdist(x: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     """Pairwise squared distances ||x_i - z_k||^2, (n, m)."""
     xx = jnp.sum(x * x, axis=-1, keepdims=True)          # (n, 1)
     zz = jnp.sum(z * z, axis=-1, keepdims=True).T        # (1, m)
-    xz = x @ z.T                                         # (n, m)
+    xz = jnp.matmul(x, z.T, precision=_HIGHEST)          # (n, m)
     return jnp.maximum(xx + zz - 2.0 * xz, 0.0)
 
 
@@ -38,8 +43,8 @@ def gram(x: jnp.ndarray, z: jnp.ndarray, kernel: KernelSpec,
     """Kernel block k(x_i, z_k) with the given backend.
 
     ``policy`` (name / DtypePolicy / None) selects the compute/accumulate
-    dtypes; None is the fp32 default and leaves this function exactly as it
-    was before policies existed (including the jnp expression tree)."""
+    dtypes; None is the fp32 default: the plain jnp expression tree, its
+    cross-term dot at HIGHEST precision."""
     if backend == "pallas":
         from repro.kernels import ops as kops
         return kops.gram(x, z, kind=kernel.kind, sigma=kernel.sigma,
@@ -53,7 +58,7 @@ def gram(x: jnp.ndarray, z: jnp.ndarray, kernel: KernelSpec,
                                      sigma=kernel.sigma,
                                      pol=pol).astype(pol.accum_dtype)
     if kernel.kind == "linear":
-        return x @ z.T
+        return jnp.matmul(x, z.T, precision=_HIGHEST)
     return jnp.exp(-sqdist(x, z) / (2.0 * kernel.sigma ** 2))
 
 

@@ -85,6 +85,9 @@ class TronResult(NamedTuple):
     n_fg: jnp.ndarray     # function/gradient evaluations (paper step 4a/4b calls)
     n_hd: jnp.ndarray     # Hessian-vector products     (paper step 4c calls)
     converged: jnp.ndarray  # scalar bool — or (K,) per column
+    f_hist: jnp.ndarray   # (max_iter + 1[, K]) objective after each outer
+    #                       iteration (entry 0: at beta0); NaN past n_iter
+    #                       and before a resumed start
 
 
 class TronSnapshot(NamedTuple):
@@ -232,6 +235,13 @@ class _TronState(NamedTuple):
     n_hd: jnp.ndarray
     gnorm0: jnp.ndarray
     active: jnp.ndarray
+    fs: jnp.ndarray       # f history, see TronResult.f_hist
+
+
+def _f_history(f, cfg: TronConfig):
+    """NaN-filled (max_iter + 1[, K]) history buffer for objectives like f."""
+    return jnp.full((cfg.max_iter + 1,) + jnp.shape(f), jnp.nan,
+                    jnp.result_type(f))
 
 
 def snapshot_of(st) -> TronSnapshot:
@@ -326,6 +336,7 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
             gnorm0=st.gnorm0,
             active=st.active & ~(run & stagnated) if multi
             else st.active & ~stagnated,
+            fs=st.fs.at[st.it + 1].set(f),
         )
 
     if state0 is None and snapshot_every <= 0 and on_snapshot is None:
@@ -339,6 +350,7 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
             n_hd=jnp.array(0, jnp.int32),
             gnorm0=gnorm0,
             active=gnorm0 > 0,
+            fs=_f_history(f0, cfg).at[0].set(f0),
         )
         st = jax.lax.while_loop(cond, body, init)     # the original program
     else:
@@ -355,11 +367,11 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
         # re-derivation re-rounds f/g/aux every `snapshot_every`
         # iterations. The re-derivations are not counted in n_fg.)
         @jax.jit
-        def _segment(beta, delta, gnorm0, active, it, n_fg, n_hd, cap):
+        def _segment(beta, delta, gnorm0, active, it, n_fg, n_hd, fs, cap):
             f, g, aux = fgrad(beta)
             st = _TronState(beta=beta, f=f, g=g, aux=aux, delta=delta,
                             it=it, n_fg=n_fg, n_hd=n_hd, gnorm0=gnorm0,
-                            active=active)
+                            active=active, fs=fs.at[it].set(f))
 
             def seg_cond(s):
                 return cond(s) & (s.it < cap)
@@ -367,7 +379,8 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
 
         def _run_segment(st, cap: int):
             return _segment(st.beta, st.delta, st.gnorm0, st.active, st.it,
-                            st.n_fg, st.n_hd, jnp.asarray(cap, jnp.int32))
+                            st.n_fg, st.n_hd, st.fs,
+                            jnp.asarray(cap, jnp.int32))
 
         def _host_live(st):
             g = np.asarray(st.g, np.float64)
@@ -388,6 +401,7 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
                 n_hd=jnp.array(0, jnp.int32),
                 gnorm0=gnorm0,
                 active=gnorm0 > 0,
+                fs=_f_history(f0, cfg),
             )
         else:
             beta_r = jnp.asarray(np.asarray(state0.beta),
@@ -402,6 +416,7 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
                 gnorm0=jnp.asarray(np.asarray(state0.gnorm0), rt),
                 active=jnp.asarray(np.asarray(state0.active, bool)) if multi
                 else jnp.asarray(bool(state0.active)),
+                fs=_f_history(jnp.zeros(np.shape(state0.gnorm0), rt), cfg),
             )
             # Zero-trip segment: rebuild f/g/aux from beta through the SAME
             # jitted program the loop uses, so even the between-segment
@@ -420,6 +435,7 @@ def tron(fgrad: Callable, hessd: Callable, beta0: jnp.ndarray,
         beta=st.beta, f=st.f, gnorm=gnorm,
         n_iter=st.it, n_fg=st.n_fg, n_hd=st.n_hd,
         converged=gnorm <= cfg.grad_rtol * st.gnorm0,
+        f_hist=st.fs,
     )
 
 
@@ -521,6 +537,8 @@ def tron_host(fgrad: Callable, hessd: Callable, beta0,
         delta = np.asarray(state0.delta, np.float64).copy()
         it, n_fg, n_hd = int(state0.it), int(state0.n_fg), int(state0.n_hd)
         active = np.asarray(state0.active, bool) & np.ones(cols, bool)
+    fs = np.full((cfg.max_iter + 1,) + f.shape, np.nan)
+    fs[it] = f
     while np.any(active & (_cnorm_np(g) > cfg.grad_rtol * gnorm0)) \
             and it < cfg.max_iter:
         gnorm = _cnorm_np(g.astype(np.float64))
@@ -575,6 +593,7 @@ def tron_host(fgrad: Callable, hessd: Callable, beta0,
         # on host would drag them off-device and re-transfer every Hd call
         aux = jax.tree.map(lambda a, b: jnp.where(accept, a, b), aux_new, aux)
         it += 1
+        fs[it] = f
 
         feps = np.abs(f) * 1e-12
         stagnated = (prered <= 0) | (
@@ -598,4 +617,5 @@ def tron_host(fgrad: Callable, hessd: Callable, beta0,
         n_fg=jnp.asarray(n_fg, jnp.int32),
         n_hd=jnp.asarray(n_hd, jnp.int32),
         converged=jnp.asarray(np.asarray(gnorm <= cfg.grad_rtol * gnorm0)),
+        f_hist=jnp.asarray(fs, jnp.float32),
     )

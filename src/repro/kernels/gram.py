@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _gram_kernel(x_ref, z_ref, o_ref, acc_ref, *, kind: str, sigma: float,
-                 out_dtype, compute=jnp.float32, accum=jnp.float32):
+                 out_dtype, compute, accum, precision):
     k = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -37,6 +37,7 @@ def _gram_kernel(x_ref, z_ref, o_ref, acc_ref, *, kind: str, sigma: float,
     x = x_ref[...].astype(compute)              # (bn, bd)
     z = z_ref[...].astype(compute)              # (bm, bd)
     xz = jax.lax.dot_general(x, z, (((1,), (1,)), ((), ())),
+                             precision=precision,
                              preferred_element_type=accum)       # (bn, bm) MXU
     if kind == "linear":
         acc_ref[...] += xz
@@ -61,10 +62,12 @@ def gram_pallas(x: jnp.ndarray, z: jnp.ndarray, *, kind: str = "gaussian",
                 sigma: float = 1.0, bn: int = 256, bm: int = 256,
                 bd: int = 256, out_dtype=jnp.float32,
                 interpret: bool = False,
-                compute=jnp.float32, accum=jnp.float32) -> jnp.ndarray:
+                compute=jnp.float32, accum=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST) -> jnp.ndarray:
     """C = k(x, z) with explicit VMEM tiling. Shapes must divide the blocks
     (the ops.py wrapper pads/unpads arbitrary shapes). ``compute``/``accum``
-    select the cross-term matmul and distance-accumulation dtypes."""
+    select the cross-term matmul and distance-accumulation dtypes,
+    ``precision`` its MXU precision (``repro.kernels.policy``)."""
     n, d = x.shape
     m, d2 = z.shape
     assert d == d2, (d, d2)
@@ -73,7 +76,7 @@ def gram_pallas(x: jnp.ndarray, z: jnp.ndarray, *, kind: str = "gaussian",
     kernel = functools.partial(_gram_kernel, kind=kind, sigma=sigma,
                                out_dtype=out_dtype,
                                compute=jnp.dtype(compute),
-                               accum=jnp.dtype(accum))
+                               accum=jnp.dtype(accum), precision=precision)
     return pl.pallas_call(
         kernel,
         grid=grid,

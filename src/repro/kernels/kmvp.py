@@ -39,8 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _tile(x_ref, z_ref, acc_ref, k, nk, kind, sigma,
-          compute=jnp.float32, accum=jnp.float32):
+def _tile(x_ref, z_ref, acc_ref, kind, compute, accum, precision):
     """Accumulate the gram tile over d-blocks; return E on the last step.
 
     ``compute`` is what the MXU multiplies (bf16 under the cheap policy),
@@ -48,10 +47,13 @@ def _tile(x_ref, z_ref, acc_ref, k, nk, kind, sigma,
     the dtype the squared norms are summed in — the VMEM scratch holding the
     running distance is always ``accum`` (f32), so only the per-tile
     products are low-precision, never the accumulation over d-blocks.
+    ``precision`` is the policy's (HIGHEST under fp32: see
+    ``repro.kernels.policy.DtypePolicy.precision``).
     """
     x = x_ref[...].astype(compute)
     z = z_ref[...].astype(compute)
     xz = jax.lax.dot_general(x, z, (((1,), (1,)), ((), ())),
+                             precision=precision,
                              preferred_element_type=accum)
     if kind == "linear":
         acc_ref[...] += xz
@@ -71,7 +73,7 @@ def _finish_tile(acc_ref, kind, sigma):
 
 
 def _kmvp_fwd_kernel(x_ref, z_ref, b_ref, o_ref, acc_ref, *, kind, sigma,
-                     compute, accum):
+                     compute, accum, precision):
     j, k = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -83,24 +85,21 @@ def _kmvp_fwd_kernel(x_ref, z_ref, b_ref, o_ref, acc_ref, *, kind, sigma,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _tile(x_ref, z_ref, acc_ref, k, nk, kind, sigma, compute, accum)
+    _tile(x_ref, z_ref, acc_ref, kind, compute, accum, precision)
 
     @pl.when(k == nk - 1)
     def _contract():
         E = _finish_tile(acc_ref, kind, sigma)                 # (bn, bm)
-        if compute == jnp.float32:
-            # fp32 policy keeps the exact pre-policy expression (bitwise).
-            o_ref[...] += E @ b_ref[...].astype(jnp.float32)   # (bn, k)
-        else:
-            # Re-cast the finished tile to compute so the RHS contraction
-            # also runs on the cheap MXU path; accumulate at accum.
-            o_ref[...] += jax.lax.dot_general(
-                E.astype(compute), b_ref[...].astype(compute),
-                (((1,), (0,)), ((), ())), preferred_element_type=accum)
+        # The finished tile is cast to compute (a no-op under fp32) so the
+        # RHS contraction runs on the same MXU path; accumulate at accum.
+        o_ref[...] += jax.lax.dot_general(
+            E.astype(compute), b_ref[...].astype(compute),
+            (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=accum)                       # (bn, k)
 
 
 def _kmvp_t_kernel(x_ref, z_ref, v_ref, g_ref, acc_ref, *, kind, sigma,
-                   compute, accum):
+                   compute, accum, precision):
     i, k = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -112,17 +111,15 @@ def _kmvp_t_kernel(x_ref, z_ref, v_ref, g_ref, acc_ref, *, kind, sigma,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _tile(x_ref, z_ref, acc_ref, k, nk, kind, sigma, compute, accum)
+    _tile(x_ref, z_ref, acc_ref, kind, compute, accum, precision)
 
     @pl.when(k == nk - 1)
     def _contract():
         E = _finish_tile(acc_ref, kind, sigma)                 # (bn, bm)
-        if compute == jnp.float32:
-            g_ref[...] += E.T @ v_ref[...].astype(jnp.float32)  # (bm, k)
-        else:
-            g_ref[...] += jax.lax.dot_general(
-                E.astype(compute), v_ref[...].astype(compute),
-                (((0,), (0,)), ((), ())), preferred_element_type=accum)
+        g_ref[...] += jax.lax.dot_general(
+            E.astype(compute), v_ref[...].astype(compute),
+            (((0,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=accum)                       # (bm, k)
 
 
 def _check_blocks(name: str, dims) -> None:
@@ -140,13 +137,15 @@ def _check_blocks(name: str, dims) -> None:
 
 def kmvp_fwd_pallas(x, z, beta, *, kind="gaussian", sigma=1.0,
                     bn=256, bm=256, bd=256, interpret=False,
-                    compute=jnp.float32, accum=jnp.float32):
+                    compute=jnp.float32, accum=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST):
     """O = C(x, z) @ B, C never materialized. B: (m, k); O: (n, k).
 
     All k right-hand-side columns share each (bn, bm) gram tile — the
     recomputation cost is paid once per tile, not once per column.
-    ``compute``/``accum`` select the tile-matmul and accumulation dtypes
-    (see ``repro.kernels.policy``); the output is always ``accum`` f32."""
+    ``compute``/``accum``/``precision`` select the tile-matmul dtype, the
+    accumulation dtype and the MXU precision (see ``repro.kernels.policy``);
+    the output is always ``accum`` f32."""
     n, d = x.shape
     m, _ = z.shape
     k = beta.shape[1]
@@ -155,7 +154,7 @@ def kmvp_fwd_pallas(x, z, beta, *, kind="gaussian", sigma=1.0,
     grid = (n // bn, m // bm, d // bd)
     kernel = functools.partial(_kmvp_fwd_kernel, kind=kind, sigma=sigma,
                                compute=jnp.dtype(compute),
-                               accum=jnp.dtype(accum))
+                               accum=jnp.dtype(accum), precision=precision)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -173,7 +172,8 @@ def kmvp_fwd_pallas(x, z, beta, *, kind="gaussian", sigma=1.0,
 
 def kmvp_t_pallas(x, z, v, *, kind="gaussian", sigma=1.0,
                   bn=256, bm=256, bd=256, interpret=False,
-                  compute=jnp.float32, accum=jnp.float32):
+                  compute=jnp.float32, accum=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST):
     """G = C(x, z)^T @ V, C never materialized. V: (n, k); G: (m, k).
 
     Adjoint of :func:`kmvp_fwd_pallas` over the same implicit C; the k
@@ -186,7 +186,7 @@ def kmvp_t_pallas(x, z, v, *, kind="gaussian", sigma=1.0,
     grid = (m // bm, n // bn, d // bd)
     kernel = functools.partial(_kmvp_t_kernel, kind=kind, sigma=sigma,
                                compute=jnp.dtype(compute),
-                               accum=jnp.dtype(accum))
+                               accum=jnp.dtype(accum), precision=precision)
     return pl.pallas_call(
         kernel,
         grid=grid,

@@ -3,8 +3,8 @@
 Handles arbitrary shapes/dtypes by zero-padding to block multiples (zero
 rows/cols are exact no-ops for both the gaussian-distance accumulation and
 the matvec contractions), picks VMEM-sane MXU-aligned block sizes, and runs
-``interpret=True`` automatically off-TPU so the same call sites work in this
-CPU container and on real hardware.
+``interpret=True`` automatically on the CPU backend (the test suite) and
+compiled kernels on a TPU; any other backend is refused.
 """
 from __future__ import annotations
 
@@ -19,7 +19,17 @@ from repro.kernels.policy import DtypePolicy, get_policy
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU (the test suite), compiled kernels on a
+    TPU; any other backend has no Mosaic lowering and no business running
+    these kernels slowly in the interpreter without saying so."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU and interpret on CPU; backend "
+        f"{backend!r} is neither (use backend='jnp')")
 
 
 def _sublane(dtype) -> int:
@@ -85,8 +95,8 @@ def gram(x, z, *, kind: str = "gaussian", sigma: float = 1.0,
          interpret: bool | None = None, policy=None):
     """C[i,k] = k(x_i, z_k) via the tiled Pallas kernel. Any shapes/dtypes.
 
-    ``policy`` (name or DtypePolicy) selects compute/accum dtypes; the
-    default fp32 policy traces exactly the pre-policy jaxpr."""
+    ``policy`` (name or DtypePolicy) selects compute/accum dtypes and the
+    dot precision (fp32 by default)."""
     if interpret is None:
         interpret = _interpret_default()
     pol = get_policy(policy)
@@ -101,7 +111,7 @@ def gram(x, z, *, kind: str = "gaussian", sigma: float = 1.0,
     zp = _pad_cols(_pad_rows(z.astype(comp), mp_), dp_)
     out = _gram.gram_pallas(xp, zp, kind=kind, sigma=sigma, bn=bn, bm=bm,
                             bd=bd, interpret=interpret, compute=comp,
-                            accum=acc)
+                            accum=acc, precision=pol.precision)
     return out[:n, :m]
 
 
@@ -134,7 +144,8 @@ def kmvp_fwd(x, z, beta, *, kind: str = "gaussian", sigma: float = 1.0,
     bp = _pad_lanes(_pad_rows(b2, mp_), interpret)  # zero padded basis rows
     out = _kmvp.kmvp_fwd_pallas(xp, zp, bp, kind=kind, sigma=sigma, bn=bn,
                                 bm=bm, bd=bd, interpret=interpret,
-                                compute=comp, accum=acc)
+                                compute=comp, accum=acc,
+                                precision=pol.precision)
     return out[:n, 0] if squeeze else out[:n, :k]
 
 
@@ -164,7 +175,8 @@ def kmvp_t(x, z, v, *, kind: str = "gaussian", sigma: float = 1.0,
     vp = _pad_lanes(_pad_rows(v2, np_), interpret)  # zero padded example rows
     out = _kmvp.kmvp_t_pallas(xp, zp, vp, kind=kind, sigma=sigma, bn=bn,
                               bm=bm, bd=bd, interpret=interpret,
-                              compute=comp, accum=acc)
+                              compute=comp, accum=acc,
+                              precision=pol.precision)
     return out[:m, 0] if squeeze else out[:m, :k]
 
 
@@ -227,6 +239,7 @@ def gram_chunk_policy(c, z, *, kind: str, sigma: float, pol: DtypePolicy):
     cc = c.astype(comp)
     zc = z.astype(comp)
     xz = jax.lax.dot_general(cc, zc, (((1,), (1,)), ((), ())),
+                             precision=pol.precision,
                              preferred_element_type=acc)
     if kind == "linear":
         return xz.astype(comp)
@@ -261,8 +274,8 @@ def kmvp_fwd_chunked(x, z, beta, *, kind: str = "gaussian", sigma: float = 1.0,
 
         @jax.checkpoint
         def chunk(c):
-            return ref.gram_ref(c, z, kind=kind, sigma=sigma) @ b2.astype(
-                jnp.float32)
+            return jnp.matmul(ref.gram_ref(c, z, kind=kind, sigma=sigma),
+                              b2.astype(jnp.float32), precision=pol.precision)
     else:
         comp, acc = pol.compute_dtype, pol.accum_dtype
         xp = _pad_rows(x.astype(comp), nb * bn).reshape(nb, bn, d)
@@ -272,6 +285,7 @@ def kmvp_fwd_chunked(x, z, beta, *, kind: str = "gaussian", sigma: float = 1.0,
         def chunk(c):
             E = gram_chunk_policy(c, z, kind=kind, sigma=sigma, pol=pol)
             return jax.lax.dot_general(E, bc, (((1,), (0,)), ((), ())),
+                                       precision=pol.precision,
                                        preferred_element_type=acc)
 
     out = jax.lax.map(chunk, xp).reshape(nb * bn, -1)[:n]
@@ -303,7 +317,8 @@ def kmvp_t_chunked(x, z, v, *, kind: str = "gaussian", sigma: float = 1.0,
         @jax.checkpoint
         def contrib(c, vc):
             E = ref.gram_ref(c, z, kind=kind, sigma=sigma)      # (bn, m)
-            return jax.lax.dot_general(vc, E, (((0,), (0,)), ((), ())))  # (k, m)
+            return jax.lax.dot_general(vc, E, (((0,), (0,)), ((), ())),
+                                       precision=pol.precision)  # (k, m)
     else:
         comp, acc = pol.compute_dtype, pol.accum_dtype
         xp = _pad_rows(x.astype(comp), nb * bn).reshape(nb, bn, d)
@@ -313,6 +328,7 @@ def kmvp_t_chunked(x, z, v, *, kind: str = "gaussian", sigma: float = 1.0,
         def contrib(c, vc):
             E = gram_chunk_policy(c, z, kind=kind, sigma=sigma, pol=pol)
             return jax.lax.dot_general(vc, E, (((0,), (0,)), ((), ())),
+                                       precision=pol.precision,
                                        preferred_element_type=acc)
 
     def body(g, cv):

@@ -18,8 +18,9 @@ to the Pallas tiles instead of being hardcoded per call site:
               symmetric per-column quantization in ``repro.checkpoint.quant``).
 
 The default policy is all-fp32 and every policied code path is written so
-that the fp32 policy traces the *identical* jaxpr as the pre-policy code —
-bitwise-unchanged behavior, asserted by tests, not just promised.
+that the fp32 policy computes bitwise what the pre-policy code computed on
+the CPU (asserted by tests); on a TPU it also asks every dot for full f32
+precision (``DtypePolicy.precision``).
 
 Policies are named (``"fp32"``, ``"bf16"``, ``"fp16"``) so they JSON
 round-trip through ``MachineConfig`` and checkpoints as plain strings.
@@ -30,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -72,6 +74,16 @@ class DtypePolicy:
         """True when every dtype is fp32 — the bitwise-unchanged fast path."""
         return (self.compute == self.accum == self.param == "float32"
                 and self.store == "float32")
+
+    @property
+    def precision(self):
+        """``precision`` of every tile contraction. On a TPU an f32 dot at
+        the default precision runs as one bf16 MXU pass, and the gaussian
+        distance ||x||^2 + ||z||^2 - 2 x.z amplifies that rounding by
+        cancellation, so fp32 asks for HIGHEST (full f32). Low-precision
+        policies multiply their own dtype, which DEFAULT does exactly."""
+        return (jax.lax.Precision.HIGHEST if self.compute == "float32"
+                else jax.lax.Precision.DEFAULT)
 
     def np_compute_dtype(self) -> np.dtype:
         """The compute dtype as a numpy dtype — what request payloads and

@@ -1,7 +1,15 @@
-"""Pure-jnp oracles for the Pallas kernels. Ground truth for all sweeps."""
+"""Pure-jnp oracles for the Pallas kernels. Ground truth for all sweeps.
+
+Every contraction asks for ``Precision.HIGHEST``: on a TPU the default f32
+dot is one bf16 pass, which no oracle may be."""
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+
+_dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
 def gram_ref(x, z, *, kind: str = "gaussian", sigma: float = 1.0):
@@ -9,21 +17,23 @@ def gram_ref(x, z, *, kind: str = "gaussian", sigma: float = 1.0):
     x = x.astype(jnp.float32)
     z = z.astype(jnp.float32)
     if kind == "linear":
-        return x @ z.T
+        return _dot(x, z.T)
     xx = jnp.sum(x * x, axis=-1, keepdims=True)
     zz = jnp.sum(z * z, axis=-1, keepdims=True).T
-    d2 = jnp.maximum(xx + zz - 2.0 * (x @ z.T), 0.0)
+    d2 = jnp.maximum(xx + zz - 2.0 * _dot(x, z.T), 0.0)
     return jnp.exp(-d2 / (2.0 * sigma ** 2))
 
 
 def kmvp_ref(x, z, beta, *, kind: str = "gaussian", sigma: float = 1.0):
     """o = C(x, z) @ beta without the caller holding C."""
-    return gram_ref(x, z, kind=kind, sigma=sigma) @ beta.astype(jnp.float32)
+    return _dot(gram_ref(x, z, kind=kind, sigma=sigma),
+                beta.astype(jnp.float32))
 
 
 def kmvp_t_ref(x, z, v, *, kind: str = "gaussian", sigma: float = 1.0):
     """g = C(x, z)^T @ v without the caller holding C."""
-    return gram_ref(x, z, kind=kind, sigma=sigma).T @ v.astype(jnp.float32)
+    return _dot(gram_ref(x, z, kind=kind, sigma=sigma).T,
+                v.astype(jnp.float32))
 
 
 def ssd_chunk_ref(Cc, Bc, dA, xdt):

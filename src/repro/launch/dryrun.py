@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, get_arch
-from repro.core import compat
 from repro.launch.mesh import make_production_mesh
 from repro.models.common import unrolled_scans, unzip
 from repro.models.config import INPUT_SHAPES, ArchConfig, ShapeSpec
@@ -178,7 +177,7 @@ def _probe_cost(cfg: ArchConfig, shape: ShapeSpec, mesh, k_periods: int,
     with unrolled_scans():
         lowered, _ = lower_step(probe, pshape, mesh, micro_override=1)
         compiled = lowered.compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     colls = collective_bytes(hlo)
     return {
@@ -321,7 +320,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         flops_dev, bytes_dev = cost["flops"], cost["bytes"]
         colls, n_coll = cost["colls"], cost["n_coll"]
     else:   # raw (while bodies counted once) — kept for debugging
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         flops_dev = float(ca.get("flops", 0.0))
         bytes_dev = float(ca.get("bytes accessed", 0.0))
         colls = collective_bytes(hlo)

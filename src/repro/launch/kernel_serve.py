@@ -46,7 +46,8 @@ import numpy as np
 
 from repro.api import KernelMachine, MachineConfig
 from repro.api.infer import BucketedDecider, bucket_rows
-from repro.launch.cli import plan_choices, registry_epilog
+from repro.launch.cli import (BACKENDS, enable_compile_cache, plan_choices,
+                              registry_epilog)
 from repro.serve import (EngineConfig, ModelRegistry, ServeEngine,
                          baseline_target, engine_target, make_workload,
                          percentiles, run_load, serving_plan)
@@ -98,14 +99,15 @@ def _train_demo_machine(path: str, n: int = 2048, m: int = 64,
 
 def serve_stream(km: KernelMachine, *, requests: int, max_batch: int,
                  seed: int = 0, d: Optional[int] = None,
-                 plan: Optional[str] = None):
+                 plan: Optional[str] = None, backend: Optional[str] = None):
     """Single-client request-at-a-time loop; returns latency stats with
     tail percentiles (p50/p95/p99 via the shared serve-metrics helper, so
     this report and the SLO load harness can never disagree)."""
     if d is None:
         from repro.serve.registry import model_dim
         d = model_dim(km)
-    endpoint = ServingEndpoint(km, max_batch=max_batch, plan=plan)
+    endpoint = ServingEndpoint(km, max_batch=max_batch, plan=plan,
+                               backend=backend)
     rng = np.random.default_rng(seed)
     sizes = rng.integers(1, max_batch + 1, size=requests)
     # warm every bucket so measured latencies are compile-free
@@ -128,15 +130,19 @@ def serve_stream(km: KernelMachine, *, requests: int, max_batch: int,
 
 
 def build_registry(ckpts, *, max_batch: int, plan: Optional[str] = None,
+                   backend: Optional[str] = None,
                    warmup: bool = True) -> ModelRegistry:
     """Load checkpoints into a registry (model names m0, m1, ... in CLI
-    order) and optionally precompile every bucket of every model."""
+    order) and optionally precompile every bucket of every model.
+    ``backend`` overrides the gram/kmvp backend each machine was trained
+    with (by default it serves through the same one)."""
     registry = ModelRegistry(max_batch=max_batch)
     for i, path in enumerate(ckpts):
-        entry = registry.load(f"m{i}", path, plan=plan)
+        entry = registry.load(f"m{i}", path, plan=plan, backend=backend)
         beta = entry.km.state_["beta"]
         print(f"[load ] {entry.name}: {path} solver={entry.km.config.solver} "
-              f"plan={entry.plan} d={entry.d} "
+              f"plan={entry.plan} "
+              f"backend={backend or entry.km.config.backend} d={entry.d} "
               f"K={beta.shape[1] if beta.ndim == 2 else 1}")
     if warmup:
         t0 = time.perf_counter()
@@ -289,7 +295,7 @@ def serve_multihost(path: str, *, requests: int, max_batch: int,
     return requests, worst
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
         epilog=registry_epilog())
@@ -310,6 +316,9 @@ def main():
                     help="decide arm override (default: each machine's own "
                          "plan; stream machines serve via 'local'; live "
                          "registry: %(choices)s)")
+    ap.add_argument("--backend", default=None, choices=BACKENDS,
+                    help="gram/kmvp backend override (default: the one each "
+                         "machine was trained with, stored in its checkpoint)")
     ap.add_argument("--serial", action="store_true",
                     help="single-client request-at-a-time loop (the "
                          "pre-engine behavior) instead of the concurrent "
@@ -328,10 +337,11 @@ def main():
                     help="total controller processes (hosts) in this run")
     ap.add_argument("--process-id", type=int, default=0,
                     help="this host's index in [0, --num-processes)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.num_processes > 1 and not args.coordinator:
         ap.error("--num-processes > 1 needs --coordinator host:port")
+    enable_compile_cache()
     multihost.init(args.coordinator, args.num_processes, args.process_id)
     if multihost.active():
         if args.selftest or args.serial:
@@ -359,20 +369,32 @@ def main():
         km = KernelMachine.load(ckpts[0])
         print(f"[load ] solver={km.config.solver} loss={km.config.loss} "
               f"state={ {k: tuple(v.shape) for k, v in km.state_.items()} }")
+        # a failed dispatch raises out of serve_stream: a non-zero exit
         _, stats = serve_stream(km, requests=args.requests,
-                                max_batch=args.max_batch, plan=args.plan)
+                                max_batch=args.max_batch, plan=args.plan,
+                                backend=args.backend)
         print(f"[serve] {stats}")
         return
 
     registry = build_registry(ckpts, max_batch=args.max_batch,
-                              plan=args.plan, warmup=not args.no_warmup)
-    _, stats = serve_concurrent(
+                              plan=args.plan, backend=args.backend,
+                              warmup=not args.no_warmup)
+    report, stats = serve_concurrent(
         registry, clients=args.clients, requests=args.requests,
         max_batch=args.max_batch,
         engine_config=EngineConfig(max_batch=args.max_batch,
                                    max_queue=args.max_queue,
                                    timeout_s=args.timeout))
     print(f"[serve] {stats}")
+    # admission-control rejections are allowed; a failed dispatch or a
+    # response that differs from its synchronous reference is not
+    if report.failed or report.mismatches or (
+            report.completed + report.rejected != report.requests):
+        raise SystemExit(
+            f"[serve] FAILED: {report.failed} failed dispatches, "
+            f"{report.mismatches} mismatched responses, "
+            f"{report.completed}+{report.rejected} of {report.requests} "
+            f"requests completed or rejected")
 
 
 if __name__ == "__main__":

@@ -20,7 +20,13 @@ Multi-host (multi-controller): run the SAME command once per host with
 then spans every process's devices, each host streams only its own
 partition of the data, and process 0 owns checkpoints/saves/eval output.
 ``scripts/launch_multihost.sh`` wraps the local N-process simulation
-(fake devices per process via ``--xla_force_host_platform_device_count``).
+(fake devices per process via ``--xla_force_host_platform_device_count``;
+a CPU simulation only — on a TPU host one process drives every chip).
+
+On a TPU, ``--backend pallas`` runs the fused Pallas kernels (the
+default ``jnp`` is the row-chunked XLA fallback); the backend is saved in
+the checkpoint. JAX's compile cache goes to ``JAX_COMPILATION_CACHE_DIR``
+when it is set, else to ``.jax_cache`` at the repo root.
 
 Any registered solver x plan combination is reachable from the CLI; the
 ``--solver``/``--plan`` choices below are read from the live registries in
@@ -46,11 +52,14 @@ from repro.data import PAPER_DATASETS, make_dataset, make_multiclass
 from repro.data.chunks import (MmapChunkSource, is_partition_dir,
                                open_partition, save_chunks)
 from repro.kernels.policy import POLICIES
-from repro.launch.cli import plan_choices, registry_epilog, solver_choices
+from repro.launch.cli import (BACKENDS, enable_compile_cache, plan_choices,
+                              registry_epilog, solver_choices)
 from repro.sharding import multihost
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return the
+    fitted :class:`KernelMachine` (the supervising parent exits instead)."""
     ap = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
         epilog=registry_epilog())
@@ -83,6 +92,12 @@ def main():
     ap.add_argument("--chunk-rows", type=int, default=None,
                     help="rows streamed per step under plan 'stream' "
                          "(bounds every intermediate at chunk_rows x m)")
+    ap.add_argument("--backend", default="jnp", choices=BACKENDS,
+                    help="gram/kmvp implementation: 'pallas' runs the fused "
+                         "Pallas kernels (compiled on a TPU, interpreted on "
+                         "the CPU), 'jnp' the row-chunked XLA fallback; "
+                         "saved into the checkpoint, so kernel_serve serves "
+                         "through the same one")
     ap.add_argument("--policy", default="fp32",
                     choices=sorted(POLICIES),
                     help="dtype policy for the kernel compute path "
@@ -125,16 +140,20 @@ def main():
                          "the fleet from the latest committed checkpoint "
                          "when a worker dies (capped exponential backoff + "
                          "jitter; shrinks the fleet after repeated failures "
-                         "— requires --ckpt-interval)")
+                         "— requires --ckpt-interval). Every process runs on "
+                         "this host, so --num-processes > 1 is a CPU "
+                         "simulation only: a TPU chip belongs to one "
+                         "process")
     ap.add_argument("--max-restarts", type=int, default=3,
                     help="restart budget under --supervise (0 = fail fast)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.supervise:
-        raise SystemExit(_supervise(ap, args))
+        raise SystemExit(_supervise(ap, args, argv))
 
     if args.num_processes > 1 and not args.coordinator:
         ap.error("--num-processes > 1 needs --coordinator host:port")
+    enable_compile_cache()
     multihost.init(args.coordinator, args.num_processes, args.process_id)
     # every process runs the same program; process 0 owns the console
     say = print if multihost.is_primary() else (lambda *a, **k: None)
@@ -214,7 +233,7 @@ def main():
             solver=args.solver, plan=args.plan,
             tron=TronConfig(max_iter=args.max_iter),
             m=m, rff_features=m, model_axis=model_axis,
-            dtype_policy=args.policy,
+            backend=args.backend, dtype_policy=args.policy,
             stream=StreamConfig(chunk_rows=args.chunk_rows))
 
     # fail on an invalid solver/plan pair before any data work
@@ -302,6 +321,11 @@ def main():
     say(f"[step3+4] {r.solver}/{r.plan}: f={r.f:.4f} iters={r.n_iter} "
         f"fg={r.n_fg} hd={r.n_hd} converged={r.converged} "
         f"({time.time() - t0:.2f}s)")
+    if r.tron is not None:
+        fh = np.asarray(r.tron.f_hist)[: r.n_iter + 1]
+        fh = fh.reshape(fh.shape[0], -1).sum(axis=1)   # one-vs-rest: total
+        say(f"[tron ] f per iteration: "
+            f"{' '.join(f'{v:.8g}' for v in fh)}")
     if ckpt is not None:
         cs = r.extras["ckpt"]
         say(f"[ckpt ] wrote {cs['snapshots_written']} step files "
@@ -324,9 +348,10 @@ def main():
             print(f"[save ] {km.save(args.save, quantize=args.quantize)}")
         multihost.sync("save")     # checkpoint durable before anyone exits
     multihost.sync("done")
+    return km
 
 
-def _supervise(ap, args) -> int:
+def _supervise(ap, args, argv=None) -> int:
     """The ``--supervise`` branch: relaunch this CLI under the supervisor.
 
     The parent never initializes a mesh — it is a pure process manager.
@@ -356,7 +381,8 @@ def _supervise(ap, args) -> int:
     # (the supervisor decides topology and resume per attempt).
     strip_valued = {"--max-restarts", "--coordinator", "--num-processes",
                     "--process-id"}
-    argv, base, i = sys.argv[1:], [], 0
+    argv = list(sys.argv[1:] if argv is None else argv)
+    base, i = [], 0
     while i < len(argv):
         tok = argv[i]
         if tok == "--supervise":

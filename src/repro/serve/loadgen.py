@@ -45,12 +45,15 @@ class LoadRequest:
 class LoadReport:
     """What one load phase measured. ``mismatches`` counts responses whose
     margins did not match the precomputed synchronous reference (bitwise
-    at atol=0, else within atol) — the acceptance criterion is zero."""
+    at atol=0, else within atol), ``failed`` requests whose dispatch
+    raised — the acceptance criterion for both is zero. ``rejected``
+    counts clean admission-control rejections."""
     label: str
     clients: int
     requests: int
     completed: int = 0
     rejected: int = 0
+    failed: int = 0
     mismatches: int = 0
     rows: int = 0
     wall_s: float = 0.0
@@ -107,7 +110,9 @@ def run_load(target: Callable[[str, np.ndarray], object],
     client keeps up to ``window`` submissions outstanding before awaiting
     the oldest — window=1 is a synchronous caller. Rejections
     (:class:`~repro.serve.batching.Rejected`, at submit or resolve time)
-    are counted, not fatal. Responses are verified against each request's
+    are counted, not fatal; so are failed dispatches (any other exception
+    a result raises), which a client survives to send the rest of its
+    stream. Responses are verified against each request's
     reference AFTER all clients finish, bitwise when ``atol`` is 0 and
     within ``atol`` otherwise, so verification cost never lands inside
     the timed region. Returns the aggregated :class:`LoadReport`."""
@@ -120,18 +125,21 @@ def run_load(target: Callable[[str, np.ndarray], object],
     start_gate = threading.Barrier(len(streams) + 1)
 
     def client(stream: List[LoadRequest]) -> None:
-        done = rejected = rows = 0
+        done = rejected = failed = rows = 0
         lats: List[float] = []
         outs: List[Tuple[LoadRequest, np.ndarray]] = []
         pending: List[Tuple[float, LoadRequest, object]] = []
 
         def harvest(entry) -> None:
-            nonlocal done, rejected, rows
+            nonlocal done, rejected, failed, rows
             t0, req, fut = entry
             try:
                 out = fut.result() if hasattr(fut, "result") else fut
             except Rejected:
                 rejected += 1
+                return
+            except Exception:            # a failed dispatch: counted
+                failed += 1
                 return
             lats.append(time.perf_counter() - t0)
             done += 1
@@ -154,6 +162,7 @@ def run_load(target: Callable[[str, np.ndarray], object],
         with lock:
             report.completed += done
             report.rejected += rejected
+            report.failed += failed
             report.rows += rows
             latencies.extend(lats)
             responses.extend(outs)

@@ -22,6 +22,11 @@ an uninterrupted run (tests/test_supervisor.py asserts this end to end).
 
 Deliberately jax-free: the supervisor is a process manager. Children do
 the jax work; the parent only needs subprocess, sockets and the stdlib.
+
+All of a fleet's processes run on this host, so more than one process is
+a CPU simulation only (each child fakes its devices). On a TPU host a
+chip belongs to one process at a time: supervise one process there, which
+drives every chip of the host.
 """
 from __future__ import annotations
 

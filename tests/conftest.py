@@ -5,12 +5,17 @@ import sys
 # NOTE: do NOT set --xla_force_host_platform_device_count here (brief:
 # smoke tests run on 1 device; multi-device tests spawn subprocesses).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# The suite keeps no persistent compile cache; the launchers' cache helper
+# (repro.launch.cli.enable_compile_cache) honours this, and subprocesses
+# the tests start inherit it.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
 import numpy as np
 import pytest
 
 jax.config.update("jax_enable_x64", False)
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 # --------------------------------------------------------------- device gating
@@ -103,3 +108,26 @@ def allclose_dtype():
     """Fixture view of :func:`assert_allclose_dtype` for tests that prefer
     injection over the conftest import."""
     return assert_allclose_dtype
+
+
+# --------------------------------------------------- f32 solver resolution
+def f32_plateau_rtol(f, lam, basis, sigma, beta):
+    """How far apart two f32 TRON solves of formulation (4) may stop, as a
+    fraction of ||beta|| (per column for an (m, K) beta).
+
+    TRON accepts a step only when the objective's f32 decrease is
+    resolvable, so a solve stops somewhere in {b : f(b) - f* <= eps |f*|}
+    (eps = f32 machine epsilon): past that, actual and predicted
+    reductions are rounding. Formulation (4) is mu-strongly convex with
+    mu = lam * lambda_min(W) (the loss term is convex), so that set lies
+    within sqrt(2 eps |f*| / mu) of the unique optimum, and two solves lie
+    within twice that of each other. ``f`` is the objective a solve
+    reported, ``basis``/``sigma`` give the gaussian W."""
+    z = np.asarray(basis, np.float64)
+    sq = np.sum(z * z, axis=1)
+    W = np.exp(-np.maximum(sq[:, None] + sq[None, :] - 2.0 * z @ z.T, 0.0)
+               / (2.0 * sigma ** 2))
+    mu = lam * np.linalg.eigvalsh(W)[0]
+    eps = np.finfo(np.float32).eps
+    radius = np.sqrt(2.0 * eps * np.abs(np.asarray(f, np.float64)) / mu)
+    return 2.0 * radius / np.linalg.norm(np.asarray(beta, np.float64), axis=0)

@@ -149,9 +149,14 @@ def run_fleet(task: str, num_processes: int, devices_per_proc: int = 1, *,
                 f"process {dead} of task {task!r} exited rc={rcs[dead]}; "
                 f"remaining workers were killed {elapsed:.1f}s in",
                 rcs, logs, elapsed)
+        # The worker's result is its last JSON line; the collectives
+        # runtime may still write status lines to the shared log after it
+        # (stdout is block-buffered, the runtime's stderr is not).
         try:
-            result = json.loads(logs[0].strip().splitlines()[-1])
-        except (IndexError, ValueError) as e:
+            result = json.loads(next(
+                ln for ln in reversed(logs[0].strip().splitlines())
+                if ln.startswith("{")))
+        except (StopIteration, ValueError) as e:
             raise FleetError(
                 f"process 0 of task {task!r} produced no JSON result ({e})",
                 rcs, logs, elapsed)

@@ -59,6 +59,7 @@ def _beta_payload(km):
     return {"beta": np.asarray(beta32, np.float64).ravel().tolist(),
             "beta_sha": hashlib.sha256(beta32.tobytes()).hexdigest(),
             "f": float(r.f), "n_iter": int(r.n_iter),
+            "basis": np.asarray(km.state_["basis"], np.float64).tolist(),
             "n_devices": jax.device_count(),
             "num_processes": multihost.process_count()}
 

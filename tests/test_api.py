@@ -48,9 +48,15 @@ def test_invalid_composition_raises_at_construction():
 
 @pytest.mark.parametrize("solver,plan", valid_combinations())
 def test_every_valid_combination_trains(data, basis, solver, plan):
-    """Registry round-trip: every solver x valid plan fits synthetic data."""
+    """Registry round-trip: every solver x valid plan fits synthetic data.
+
+    rff draws 256 features here, not CFG's 64: over eight seeds 64 random
+    features score 0.904 +- 0.031 on this split (seed 0: 0.844), so a
+    fixed 0.85 bar tested the draw; 256 score 0.978 +- 0.010. The other
+    solvers ignore rff_features."""
     X, y, Xt, yt = data
-    km = KernelMachine(CFG.replace(solver=solver, plan=plan))
+    km = KernelMachine(CFG.replace(solver=solver, plan=plan,
+                                   rff_features=256))
     km.fit(X, y, basis if get_solver(solver).needs_basis else None)
     assert km.result_.solver == solver and km.result_.plan == plan
     assert km.score(Xt, yt) > 0.85

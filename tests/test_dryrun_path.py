@@ -26,7 +26,6 @@ from repro.sharding.ctx import use_shard_hints
 from repro.sharding.partitioning import batch_specs, cache_pspecs, param_specs
 from repro.train.steps import make_serve_step, make_train_step
 
-from repro.core import compat
 from repro.core.compat import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 out = {}
@@ -54,7 +53,7 @@ for name in ("tinyllama-1.1b", "mamba2-1.3b", "grok-1-314b",
                           donate_argnums=(0, 1)).lower(
             params_sds, opt_sds, batch_sds)
         compiled = lowered.compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     # decode path
     dshape = ShapeSpec("d", 64, 8, "decode")
     cache_sds = cache_specs(cfg, dshape)

@@ -73,7 +73,13 @@ def test_microbatched_train_step_matches_full_batch():
                                           cfg.vocab),
              "labels": jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0,
                                           cfg.vocab)}
-    ocfg = AdamWConfig(lr=1e-3)
+    # A first AdamW step moves each weight by lr*g/(|g|+eps), whose slope
+    # in g is lr/eps. Accumulating over microbatches reorders the f32 sums,
+    # moving g by up to ~eps_f32*max|g| (4e-9 here); with the default
+    # eps=1e-8 a weight whose gradient is itself rounding-sized (3e-9 in
+    # ffn w2) then moves by 2e-4, which compares rounding, not the
+    # accumulation. eps=1e-6 bounds that term by lr*4e-9/1e-6 = 4e-6.
+    ocfg = AdamWConfig(lr=1e-3, eps=1e-6)
     opt = adamw_init(params, ocfg)
     p1, _, m1 = jax.jit(make_train_step(model, ocfg))(params, opt, batch)
     p4, _, m4 = jax.jit(make_train_step(model, ocfg, microbatches=4))(

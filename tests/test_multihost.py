@@ -9,9 +9,11 @@ into one global mesh (tests/multihost/rig.py).
 Three properties are load-bearing:
 
 * **Parity** — the fit over 2 and 4 processes matches the single-process
-  beta to 1e-4 relative, and 2-process x 2-device equals 4-process x
-  1-device *bitwise* (same 4-device global mesh, same reduction order):
-  the distribution layer changes where rows live, not the math.
+  beta as closely as two f32 solves can stop (the derived plateau
+  tolerance of ``conftest.f32_plateau_rtol``), and 2-process x 2-device
+  equals 4-process x 1-device *bitwise* (same 4-device global mesh, same
+  reduction order): the distribution layer changes where rows live, not
+  the math.
 * **O(m) traffic** — the cross-host payload of one training chunk
   evaluation is counted from the traced jaxpr (not claimed): a handful
   of m-vectors, independent of chunk_rows; a served request moves
@@ -21,6 +23,7 @@ Three properties are load-bearing:
 """
 import numpy as np
 import pytest
+from conftest import f32_plateau_rtol
 
 from multihost.rig import FleetError, run_fleet
 
@@ -38,8 +41,10 @@ def _rel_l2(a, b):
 
 @pytest.mark.parametrize("plan", PLANS)
 def test_multihost_parity_and_elasticity(plan):
-    """2- and 4-process fits match 1-process at 1e-4 rel; 2x2 == 4x1
-    bitwise. All three fleets share the 4-device global mesh."""
+    """2- and 4-process fits match 1-process within the f32 plateau
+    tolerance (lam 0.1, sigma 1 as in worker._config; a few 1e-3 of
+    ||beta|| here); 2x2 == 4x1 bitwise. All three fleets share the
+    4-device global mesh."""
     ref = run_fleet("fit", 1, 4, extra=[plan]).result
     two = run_fleet("fit", 2, 2, extra=[plan]).result
     four = run_fleet("fit", 4, 1, extra=[plan]).result
@@ -48,8 +53,12 @@ def test_multihost_parity_and_elasticity(plan):
     assert two["num_processes"] == 2 and four["num_processes"] == 4
     rel2 = _rel_l2(two["beta"], ref["beta"])
     rel4 = _rel_l2(four["beta"], ref["beta"])
-    assert rel2 < 1e-4, f"2-process beta diverged: rel l2 {rel2:.2e}"
-    assert rel4 < 1e-4, f"4-process beta diverged: rel l2 {rel4:.2e}"
+    rtol = float(f32_plateau_rtol(ref["f"], 0.1, ref["basis"], 1.0,
+                                  ref["beta"]))
+    assert rel2 < rtol, f"2-process beta diverged: rel l2 {rel2:.2e} " \
+        f"(tolerance {rtol:.2e})"
+    assert rel4 < rtol, f"4-process beta diverged: rel l2 {rel4:.2e} " \
+        f"(tolerance {rtol:.2e})"
     # process count is a deployment knob, not a numerical one: identical
     # global device count -> identical reduction order -> identical bits
     assert two["beta_sha"] == four["beta_sha"], \

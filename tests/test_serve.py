@@ -138,3 +138,36 @@ def test_served_multiclass_labels_equal_predict(km_mc):
         labels = km_mc.state_["classes"][jnp.argmax(served, axis=-1)]
         np.testing.assert_array_equal(np.asarray(labels),
                                       np.asarray(km_mc.predict(Xq)))
+
+
+# ------------------------------------------------------------- CLI exit code
+def test_kernel_serve_exits_nonzero_on_failed_dispatch(km, tmp_path):
+    """A dispatch that raises fails only its batch inside the engine, but
+    the serving run as a whole must not report success: kernel_serve
+    counts the failed request and exits non-zero. The same run without
+    the fault exits cleanly."""
+    from repro.faults import FaultPlan
+    from repro.launch import kernel_serve
+    path = str(tmp_path / "m.npz")
+    km.save(path)
+    argv = ["--ckpt", path, "--clients", "2", "--requests", "4",
+            "--max-batch", "16"]
+    kernel_serve.main(argv)                       # no fault: returns
+    with FaultPlan().inject("serve.dispatch", exc="RuntimeError", times=1):
+        with pytest.raises(SystemExit) as ei:
+            kernel_serve.main(argv)
+    assert "1 failed dispatches" in str(ei.value.code), ei.value.code
+
+
+def test_kernel_serve_backend_override(km, tmp_path):
+    """--backend replaces the backend a machine was trained with (jnp
+    here) for every served model, and margins agree across the two."""
+    from repro.launch.kernel_serve import build_registry
+    path = str(tmp_path / "m.npz")
+    km.save(path)
+    Xq = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (9, D)))
+    trained = build_registry([path], max_batch=16, warmup=False).get("m0")
+    pallas = build_registry([path], max_batch=16, backend="pallas",
+                            warmup=False).get("m0")
+    np.testing.assert_allclose(pallas.decider(Xq), trained.decider(Xq),
+                               rtol=1e-5, atol=1e-6)
