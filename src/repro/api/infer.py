@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.compat import default_mesh, shard_map
 from repro.core.nystrom import KernelSpec, gram
 from repro.data.chunks import (ArrayChunkSource, ChunkSource,
@@ -352,13 +353,19 @@ class BucketedDecider:
         trimming happen host-side in numpy — only the bucket-shaped
         executable itself touches XLA, so no request size ever triggers an
         eager pad/slice compile (those one-off ~100 ms stalls would
-        dominate tail latency)."""
-        X = np.asarray(X)
+        dominate tail latency). Each call is one ``infer.decide`` span
+        (:mod:`repro.obs`): pad, run, copy the margins to the host."""
+        with obs.span("infer.decide"):
+            X = np.asarray(X)
+            n = X.shape[0]
+            if n > self.max_batch:      # split oversize (coalesced) blocks
+                return np.concatenate(
+                    [self._bucketed(X[i:i + self.max_batch])
+                     for i in range(0, n, self.max_batch)])
+            return self._bucketed(X)
+
+    def _bucketed(self, X: np.ndarray) -> np.ndarray:
         n = X.shape[0]
-        if n > self.max_batch:          # split oversize (coalesced) blocks
-            parts = [self(X[i:i + self.max_batch])
-                     for i in range(0, n, self.max_batch)]
-            return np.concatenate(parts)
         b = bucket_rows(n, self.max_batch)
         if b != n:
             Xp = np.zeros((b,) + X.shape[1:], X.dtype)

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.config import MachineConfig
 from repro.api.infer import (_is_chunked, as_inference_source,
                              iter_label_chunks, make_stream_decider)
@@ -95,48 +96,54 @@ class KernelMachine:
         in ``checkpoint.dir`` — including its stored basis (and one-vs-rest
         class order), so the restarted run optimizes the identical
         objective — and continues from that iterate.
+
+        Each call is one ``machine.fit`` span (:mod:`repro.obs`), the
+        parent of its ``estimator.solve`` and ``estimator.wait`` spans.
         """
-        entry = validate(self.config.solver, self.config.plan)
-        resume = None
-        if checkpoint is not None:
-            if self.config.solver != "tron":
-                raise ValueError(
-                    f"in-training checkpoints snapshot TRON iterate state; "
-                    f"solver {self.config.solver!r} does not support "
-                    f"checkpoint= (use solver='tron')")
-            if checkpoint.resume:
-                resume = load_latest(checkpoint.dir)
-                check_resume_config(self.config, resume.meta)
-                if "basis" in resume.arrays:
-                    # the stored basis IS the objective's identity: never
-                    # re-select (a fresh random draw would change k(x, basis))
-                    basis = jnp.asarray(resume.arrays["basis"])
-        if key is None:
-            key = jax.random.PRNGKey(self.config.seed)
-        if basis is None and entry.needs_basis:
-            from repro.data.chunks import ChunkSource, random_basis_from_source
-            if isinstance(X, ChunkSource):   # out-of-core: O(m) rows read
-                if self.config.basis_strategy not in ("random", "auto"):
+        with obs.span("machine.fit"):
+            entry = validate(self.config.solver, self.config.plan)
+            resume = None
+            if checkpoint is not None:
+                if self.config.solver != "tron":
                     raise ValueError(
-                        f"basis_strategy {self.config.basis_strategy!r} "
-                        f"needs X in memory; chunked sources support "
-                        f"'random' (or pass an explicit basis)")
-                basis = jnp.asarray(random_basis_from_source(
-                    key, X, self.config.m))
-            else:
-                basis = select_basis(key, X, self.config.m,
-                                     strategy=self.config.basis_strategy,
-                                     mesh=self.mesh,
-                                     data_axes=self.config.data_axes)
-        hooks = {} if checkpoint is None else {"checkpoint": checkpoint,
-                                               "resume": resume}
-        state, res = entry.fit(self.config, X, y, basis, beta0,
-                               mesh=self.mesh, plan=self.config.plan, key=key,
-                               **hooks)
-        self.state_ = state
-        self.history_ = [res]
-        self._cw = self._cw_key = None
-        return self
+                        f"in-training checkpoints snapshot TRON iterate "
+                        f"state; solver {self.config.solver!r} does not "
+                        f"support checkpoint= (use solver='tron')")
+                if checkpoint.resume:
+                    resume = load_latest(checkpoint.dir)
+                    check_resume_config(self.config, resume.meta)
+                    if "basis" in resume.arrays:
+                        # the stored basis IS the objective's identity:
+                        # never re-select (a fresh random draw would change
+                        # k(x, basis))
+                        basis = jnp.asarray(resume.arrays["basis"])
+            if key is None:
+                key = jax.random.PRNGKey(self.config.seed)
+            if basis is None and entry.needs_basis:
+                from repro.data.chunks import (ChunkSource,
+                                               random_basis_from_source)
+                if isinstance(X, ChunkSource):   # out-of-core: O(m) rows read
+                    if self.config.basis_strategy not in ("random", "auto"):
+                        raise ValueError(
+                            f"basis_strategy {self.config.basis_strategy!r} "
+                            f"needs X in memory; chunked sources support "
+                            f"'random' (or pass an explicit basis)")
+                    basis = jnp.asarray(random_basis_from_source(
+                        key, X, self.config.m))
+                else:
+                    basis = select_basis(key, X, self.config.m,
+                                         strategy=self.config.basis_strategy,
+                                         mesh=self.mesh,
+                                         data_axes=self.config.data_axes)
+            hooks = {} if checkpoint is None else {"checkpoint": checkpoint,
+                                                   "resume": resume}
+            state, res = entry.fit(self.config, X, y, basis, beta0,
+                                   mesh=self.mesh, plan=self.config.plan,
+                                   key=key, **hooks)
+            self.state_ = state
+            self.history_ = [res]
+            self._cw = self._cw_key = None
+            return self
 
     def partial_fit(self, X, y, new_basis, *, key=None):
         """Stage-wise basis growth (paper §3): add ``new_basis`` points,

@@ -58,6 +58,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.api.infer import decide_fused, decide_local, decide_stream
 from repro.api.registry import register_plan
 from repro.core.compat import default_mesh
@@ -73,31 +74,33 @@ def plan_local(config, mesh, X, y, basis, beta0,
                CW: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
                classes=None, checkpoint=None, state0=None) -> TronResult:
     del mesh, classes   # multiclass y arrives pre-expanded to (n, K) ±1
-    pol = None if config.dtype_policy == "fp32" else config.dtype_policy
-    if CW is None:
-        C = build_C(X, basis, config.kernel, config.backend, policy=pol)
-        W = build_W(basis, config.kernel, config.backend, policy=pol)
-    else:
-        C, W = CW
-    form = Formulation4(lam=config.lam, loss=config.get_loss())
-    cfg = config.tron
+    with obs.span("estimator.solve"):
+        pol = None if config.dtype_policy == "fp32" else config.dtype_policy
+        if CW is None:
+            C = build_C(X, basis, config.kernel, config.backend, policy=pol)
+            W = build_W(basis, config.kernel, config.backend, policy=pol)
+        else:
+            C, W = CW
+        form = Formulation4(lam=config.lam, loss=config.get_loss())
+        cfg = config.tron
 
-    if checkpoint is not None or state0 is not None:
-        # tron jits its own while_loop segments and snapshots between them;
-        # an outer jit here would hide the state from the host
-        return tron(lambda b: form.fgrad(C, W, y, b),
-                    lambda D, d: form.hessd(C, W, D, d), beta0, cfg,
-                    state0=state0,
-                    snapshot_every=checkpoint.interval if checkpoint else 0,
-                    on_snapshot=checkpoint.on_snapshot if checkpoint
-                    else None)
+        if checkpoint is not None or state0 is not None:
+            # tron jits its own while_loop segments and snapshots between them;
+            # an outer jit here would hide the state from the host
+            return tron(lambda b: form.fgrad(C, W, y, b),
+                        lambda D, d: form.hessd(C, W, D, d), beta0, cfg,
+                        state0=state0,
+                        snapshot_every=checkpoint.interval if checkpoint
+                        else 0,
+                        on_snapshot=checkpoint.on_snapshot if checkpoint
+                        else None)
 
-    @jax.jit
-    def _run(C, W, y, beta0):
-        return tron(lambda b: form.fgrad(C, W, y, b),
-                    lambda D, d: form.hessd(C, W, D, d), beta0, cfg)
+        @jax.jit
+        def _run(C, W, y, beta0):
+            return tron(lambda b: form.fgrad(C, W, y, b),
+                        lambda D, d: form.hessd(C, W, D, d), beta0, cfg)
 
-    return _run(C, W, y, beta0)
+        return _run(C, W, y, beta0)
 
 
 def _axis_extent(mesh, axes) -> int:

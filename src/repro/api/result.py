@@ -12,6 +12,7 @@ import dataclasses
 import math
 from typing import Any, Dict, Optional
 
+from repro import obs
 from repro.core.tron import TronResult
 
 
@@ -35,14 +36,16 @@ class FitResult:
         f/gnorm/converged; the scalar summary here is the separable total
         objective (sum), the worst gradient norm, and all-columns
         convergence. The raw per-column result stays in ``extras['tron']``.
+        Reading the result waits for the device: an ``estimator.wait`` span.
         """
         import numpy as np
         ex = {"tron": res}
         if extras:
             ex.update(extras)
-        f = np.asarray(res.f)
-        gnorm = np.asarray(res.gnorm)
-        conv = np.asarray(res.converged)
+        with obs.span("estimator.wait"):
+            f = np.asarray(res.f)
+            gnorm = np.asarray(res.gnorm)
+            conv = np.asarray(res.converged)
         return cls(solver=solver, plan=plan, m=m,
                    f=float(f.sum()), gnorm=float(gnorm.max()),
                    n_iter=int(res.n_iter), n_fg=int(res.n_fg),

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.compat import shard_map
 # the rank-generic reductions (_colsum, and _ct_v with its XLA-CPU
 # transpose-avoidance NOTE) are shared with the local-math module
@@ -847,58 +848,62 @@ class DistributedNystrom:
     def solve(self, X, y, basis, beta0=None,
               cfg: TronConfig = TronConfig(), checkpoint=None,
               state0=None) -> TronResult:
-        if multihost.active():
-            # in-memory fit on a process-spanning mesh: X/y become global
-            # row-sharded arrays (this process supplies only its block),
-            # basis/beta replicas — after which the closures below compile
-            # to the exact single-process program, psums included
-            multihost.check_mesh_spans(self.mesh)
-            X = self._as_global_rows(X)
-            y = self._as_global_rows(y)
-            basis = self._as_replicated(basis)
-            if beta0 is not None:
-                beta0 = self._as_replicated(beta0)
-            if not self.dist.fused or self.dist.materialize:
-                raise ValueError(
-                    "multi-controller in-memory fits route through the "
-                    "fused rows-only closures (plan 'otf_shard'); other "
-                    "in-memory plans are rejected at machine construction")
-            if checkpoint is not None or state0 is not None:
-                raise ValueError(
-                    "checkpointed multi-controller fits use plan 'stream' "
-                    "(the paper's deployment shape — tron_host snapshots "
-                    "between passes); the in-memory 'otf_shard' traced "
-                    "driver cannot hand process-spanning state to the host "
-                    "mid-trace")
+        """TRON over the in-memory plan's closures, returned as soon as the
+        program is enqueued; one ``estimator.solve`` span (closures, jit
+        trace, compile or cache load, ``precompute``)."""
+        with obs.span("estimator.solve"):
+            if multihost.active():
+                # in-memory fit on a process-spanning mesh: X/y become global
+                # row-sharded arrays (this process supplies only its block),
+                # basis/beta replicas — after which the closures below compile
+                # to the exact single-process program, psums included
+                multihost.check_mesh_spans(self.mesh)
+                X = self._as_global_rows(X)
+                y = self._as_global_rows(y)
+                basis = self._as_replicated(basis)
+                if beta0 is not None:
+                    beta0 = self._as_replicated(beta0)
+                if not self.dist.fused or self.dist.materialize:
+                    raise ValueError(
+                        "multi-controller in-memory fits route through the "
+                        "fused rows-only closures (plan 'otf_shard'); other "
+                        "in-memory plans are rejected at machine construction")
+                if checkpoint is not None or state0 is not None:
+                    raise ValueError(
+                        "checkpointed multi-controller fits use plan 'stream' "
+                        "(the paper's deployment shape — tron_host snapshots "
+                        "between passes); the in-memory 'otf_shard' traced "
+                        "TRON loop cannot hand process-spanning state to the "
+                        "host mid-trace")
+                if beta0 is None:
+                    beta0 = self._as_replicated(
+                        np.zeros((basis.shape[0],), np.dtype(X.dtype)))
+
+            if self.dist.materialize:
+                C, W = self.precompute(X, basis)
+                make, data = self.make_closures, (C, W, y)
+            elif self.dist.fused:
+                make, data = self.make_fused_closures, (X, y, basis)
+            else:
+                make, data = self.make_otf_closures, (X, y, basis)
             if beta0 is None:
-                beta0 = self._as_replicated(
-                    np.zeros((basis.shape[0],), np.dtype(X.dtype)))
+                beta0 = jnp.zeros((basis.shape[0],), X.dtype)
 
-        if self.dist.materialize:
-            C, W = self.precompute(X, basis)
-            make, data = self.make_closures, (C, W, y)
-        elif self.dist.fused:
-            make, data = self.make_fused_closures, (X, y, basis)
-        else:
-            make, data = self.make_otf_closures, (X, y, basis)
-        if beta0 is None:
-            beta0 = jnp.zeros((basis.shape[0],), X.dtype)
+            if checkpoint is None and state0 is None:
+                # the data are arguments, not closure constants: a closed-over
+                # array would be baked into the program (X twice on the device,
+                # a compile-cache key per dataset), and a process-spanning one
+                # may not be closed over at all
+                @jax.jit
+                def _run(data, beta0):
+                    return tron(*make(*data), beta0, cfg)
 
-        if checkpoint is None and state0 is None:
-            # the data are arguments, not closure constants: a closed-over
-            # array would be baked into the program (X twice on the device,
-            # a compile-cache key per dataset), and a process-spanning one
-            # may not be closed over at all
-            @jax.jit
-            def _run(data, beta0):
-                return tron(*make(*data), beta0, cfg)
-
+                with self.mesh:
+                    return _run(data, beta0)
+            # checkpointed/resumed: tron segments its own jitted while_loop so
+            # the host can snapshot between segments (no outer jit here)
             with self.mesh:
-                return _run(data, beta0)
-        # checkpointed/resumed: tron segments its own jitted while_loop so
-        # the host can snapshot between segments (no outer jit here)
-        with self.mesh:
-            return tron(
-                *make(*data), beta0, cfg, state0=state0,
-                snapshot_every=checkpoint.interval if checkpoint else 0,
-                on_snapshot=checkpoint.on_snapshot if checkpoint else None)
+                return tron(
+                    *make(*data), beta0, cfg, state0=state0,
+                    snapshot_every=checkpoint.interval if checkpoint else 0,
+                    on_snapshot=checkpoint.on_snapshot if checkpoint else None)

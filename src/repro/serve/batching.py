@@ -16,10 +16,11 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-import time
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from repro import obs
 
 
 class Rejected(RuntimeError):
@@ -80,12 +81,16 @@ class ServeFuture:
 
 @dataclasses.dataclass
 class Request:
-    """One admitted request: rows for one model plus its completion slot."""
+    """One admitted request: rows for one model plus its completion slot.
+
+    Times are on :func:`repro.obs.clock`; ``id`` names the request's
+    ``serve.queue`` span."""
     model: str
     X: np.ndarray
     future: ServeFuture
-    deadline: Optional[float]      # time.monotonic() cutoff, None = never
+    deadline: Optional[float]      # obs.clock() cutoff, None = never
     submitted_at: float
+    id: int = obs.NO_PARENT
 
     @property
     def n(self) -> int:
@@ -156,13 +161,13 @@ class RequestQueue:
 
         Returns ``None`` on timeout with an empty queue. ``live`` may be
         empty if every popped request had already expired."""
-        now = time.monotonic()
+        now = obs.clock()
         with self._lock:
             if not self._total:
                 self._nonempty.wait(wait_s)
                 if not self._total:
                     return None
-                now = time.monotonic()
+                now = obs.clock()
             model = self._order[0]
             dq = self._pending[model]
             live: List[Request] = []

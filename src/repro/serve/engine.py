@@ -35,17 +35,22 @@ model into fast :class:`~repro.serve.batching.CircuitOpen` rejections at
 submit (then probes its way closed again after a cooldown), and the
 engine-level health gauge (STARTING/READY/DEGRADED/DRAINING) is exposed
 through ``ServeMetrics``.
+
+Spans (:mod:`repro.obs`): every queued request records ``serve.queue``
+(submit to the batcher's pop, timed out, failed and cancelled requests
+included) under its own id, naming as parent the ``serve.dispatch`` span
+that took it; ``serve.dispatch`` covers one dispatch from block assembly
+to the last future set, with the decide arm's ``infer.decide`` inside it.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from typing import Dict, Optional, Sequence
 
 import numpy as np
 
-from repro import faults
+from repro import faults, obs
 from repro.api.infer import scatter_rows
 from repro.serve.batching import (CircuitOpen, EngineStopped, QueueFull,
                                   Request, RequestQueue, RequestTimeout,
@@ -144,9 +149,10 @@ class ServeEngine:
         if self._thread is not None:
             self._thread.join(timeout)
             self._thread = None
-        for req in self._queue.drain():
-            self._finish(req, exc=EngineStopped("serve engine stopped"),
-                         counter="cancelled")
+        drained = self._queue.drain()
+        self._settle(drained, "cancelled", obs.clock())
+        for req in drained:
+            req.future.set_exception(EngineStopped("serve engine stopped"))
         self.metrics.set_health(STARTING)   # stopped = not serving yet
 
     def __enter__(self) -> "ServeEngine":
@@ -205,10 +211,10 @@ class ServeEngine:
             self.metrics.add(completed=1)
             return future
         timeout_s = self.config.timeout_s if timeout is _UNSET else timeout
-        now = time.monotonic()
+        now = obs.clock()
         req = Request(model=entry.name, X=X, future=future,
                       deadline=None if timeout_s is None else now + timeout_s,
-                      submitted_at=now)
+                      submitted_at=now, id=obs.next_id())
         with self._inflight_lock:
             if self._inflight >= self.config.max_inflight:
                 self.metrics.add(rejected_full=1)
@@ -240,16 +246,20 @@ class ServeEngine:
             return self._inflight
 
     # ----------------------------------------------------------- batching
-    def _finish(self, req: Request, *, result: Optional[np.ndarray] = None,
-                exc: Optional[BaseException] = None,
-                counter: str = "completed") -> None:
+    def _settle(self, reqs: Sequence[Request], counter: str, popped: float,
+                parent: int = obs.NO_PARENT) -> None:
+        """Free a batch's in-flight slots, count it under ``counter`` and
+        record each request's ``serve.queue`` span (submit to ``popped``),
+        once per batch: the process's Python work per request shows in the
+        latency of every request queued behind it. The caller then
+        resolves the futures."""
+        if not reqs:
+            return
         with self._inflight_lock:
-            self._inflight -= 1
-        self.metrics.add(**{counter: 1})
-        if exc is not None:
-            req.future.set_exception(exc)
-        else:
-            req.future.set_result(result)
+            self._inflight -= len(reqs)
+        self.metrics.add(**{counter: len(reqs)})
+        obs.record_many("serve.queue", [r.submitted_at for r in reqs],
+                        popped, [r.id for r in reqs], parent)
 
     def _batch_loop(self) -> None:
         cfg = self.config
@@ -257,46 +267,51 @@ class ServeEngine:
             batch = self._queue.next_batch(cfg.max_batch, cfg.poll_s)
             if batch is None:
                 continue
+            popped = obs.clock()
             model, live, expired = batch
+            self._settle(expired, "rejected_timeout", popped)
             for req in expired:
-                self._finish(req, exc=_timeout_error(req),
-                             counter="rejected_timeout")
+                req.future.set_exception(_timeout_error(req, popped))
             if live:
-                self._dispatch(model, live)
+                self._dispatch(model, live, popped)
 
-    def _dispatch(self, model: str, reqs: Sequence[Request]) -> None:
-        sizes = [r.n for r in reqs]
-        rows = sum(sizes)
-        try:
-            # registry lookup and block assembly are inside the guard too: a
-            # model unregistered mid-flight (or a bad request that slipped
-            # admission) must fail ITS batch, not kill the batcher thread
-            # with every in-flight slot still held
-            faults.fire("serve.dispatch", detail=model)
-            entry = self.registry.get(model)
-            block = reqs[0].X if len(reqs) == 1 \
-                else np.concatenate([r.X for r in reqs], axis=0)
-            margins = np.asarray(entry.decider(block))
-        except Exception as exc:         # fail the batch, keep serving
-            if self._breaker(model).record_failure():
-                self.metrics.add(breaker_opened=1)
+    def _dispatch(self, model: str, reqs: Sequence[Request],
+                  popped: float) -> None:
+        with obs.span("serve.dispatch") as span:
+            sizes = [r.n for r in reqs]
+            rows = sum(sizes)
+            try:
+                # registry lookup and block assembly are inside the guard
+                # too: a model unregistered mid-flight (or a bad request
+                # that slipped admission) must fail ITS batch, not kill the
+                # batcher thread with every in-flight slot still held
+                faults.fire("serve.dispatch", detail=model)
+                entry = self.registry.get(model)
+                block = reqs[0].X if len(reqs) == 1 \
+                    else np.concatenate([r.X for r in reqs], axis=0)
+                margins = np.asarray(entry.decider(block))
+            except Exception as exc:         # fail the batch, keep serving
+                if self._breaker(model).record_failure():
+                    self.metrics.add(breaker_opened=1)
+                    self._update_health()
+                self._settle(reqs, "failed", popped, span.id)
+                for req in reqs:
+                    req.future.set_exception(exc)
+                return
+            if self._breaker(model).record_success():
+                self.metrics.add(breaker_closed=1)
                 self._update_health()
-            for req in reqs:
-                self._finish(req, exc=exc, counter="failed")
-            return
-        if self._breaker(model).record_success():
-            self.metrics.add(breaker_closed=1)
-            self._update_health()
-        self.metrics.add(dispatches=1, dispatched_rows=rows,
-                         padded_rows=entry.decider.padded_rows(rows),
-                         coalesced_requests=len(reqs))
-        for req, part in zip(reqs, scatter_rows(margins, sizes)):
-            # copy: the caller's slice must not pin the whole block alive
-            self._finish(req, result=np.array(part, copy=True))
+            self.metrics.add(dispatches=1, dispatched_rows=rows,
+                             padded_rows=entry.decider.padded_rows(rows),
+                             coalesced_requests=len(reqs))
+            self._settle(reqs, "completed", popped, span.id)
+            for req, part in zip(reqs, scatter_rows(margins, sizes)):
+                # copy: the caller's slice must not pin the block alive
+                req.future.set_result(np.array(part, copy=True))
 
 
-def _timeout_error(req: Request) -> RequestTimeout:
-    waited = time.monotonic() - req.submitted_at
+def _timeout_error(req: Request, popped: float) -> RequestTimeout:
+    waited = popped - req.submitted_at
     return RequestTimeout(
         f"request for model {req.model!r} ({req.n} rows) expired after "
         f"{waited * 1e3:.0f} ms in queue")

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.api import (KernelMachine, MachineConfig, available_plans,
                        available_solvers, get_solver, valid_combinations,
                        validate)
@@ -110,6 +111,25 @@ def test_same_fit_call_under_every_plan(data, basis, plan):
     assert abs(km.result_.f - km_ref.result_.f) / abs(km_ref.result_.f) < 1e-4
     assert float(jnp.max(jnp.abs(km.state_["beta"] -
                                  km_ref.state_["beta"]))) < 1e-2
+
+
+@pytest.mark.parametrize("plan", ["local", "shard_map", "otf_shard"])
+def test_fit_records_one_machine_fit_with_estimator_children(data, basis,
+                                                             plan):
+    X, y, _, _ = data
+    km = KernelMachine(CFG.replace(plan=plan, tron=TronConfig(max_iter=3)))
+    t0 = obs.clock()
+    km.fit(X, y, basis)
+    km.fit(X, y, basis)
+    t1 = obs.clock()
+    fits = obs.spans("machine.fit", t0, t1)
+    solve = obs.spans("estimator.solve", t0, t1)
+    wait = obs.spans("estimator.wait", t0, t1)
+    assert len(fits) == len(solve) == len(wait) == 2
+    assert list(solve["parent"]) == list(wait["parent"]) == list(fits["id"])
+    for f, s, w in zip(fits, solve, wait):
+        assert f["start"] <= s["start"] <= s["end"] <= w["start"] \
+            <= w["end"] <= f["end"]
 
 
 # ---------------------------------------------------------------- save/load

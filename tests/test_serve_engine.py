@@ -19,6 +19,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.api import KernelMachine, MachineConfig
 from repro.api.infer import BucketedDecider, bucket_rows, scatter_rows
 from repro.core import KernelSpec, TronConfig, random_basis
@@ -318,6 +319,98 @@ def test_dispatch_failure_releases_slots_and_keeps_batcher_alive(registry):
     assert engine(X, model="bin").shape == (2,)   # batcher still alive
     assert engine.inflight == 0
     engine.stop()
+
+
+# ---------------------------------------------------------------- spans
+def _spans(t0, *names):
+    t1 = obs.clock()
+    return [obs.spans(n, t0, t1) for n in names]
+
+
+def test_spans_match_the_metrics_on_a_clean_run(registry):
+    """One serve.dispatch per dispatch and one serve.queue per completed
+    request; every request's queue record names an existing dispatch, and
+    every infer.decide lies inside its dispatch."""
+    t0 = obs.clock()
+    with ServeEngine(registry, EngineConfig(max_batch=32)) as engine:
+        before = engine.metrics.snapshot()
+        rng = np.random.default_rng(0)
+
+        def client(k):
+            for _ in range(25):
+                engine(rng.standard_normal((1 + k, D)).astype(np.float32))
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        after = engine.metrics.snapshot()
+    disp, queue, decide = _spans(t0, "serve.dispatch", "serve.queue",
+                                 "infer.decide")
+    assert len(disp) == after["dispatches"] - before["dispatches"] > 0
+    assert len(queue) == after["completed"] - before["completed"] == 100
+    assert len(set(queue["id"])) == 100
+    assert set(queue["parent"]) <= set(disp["id"])
+    assert list(decide["parent"]) == list(disp["id"])
+    by_id = {r["id"]: r for r in disp}
+    for d in decide:
+        p = by_id[d["parent"]]
+        assert p["start"] <= d["start"] <= d["end"] <= p["end"]
+    for q in queue:                  # popped before its dispatch began
+        assert q["start"] <= q["end"] <= by_id[q["parent"]]["start"]
+
+
+def test_refused_requests_close_their_spans(registry):
+    """A request that timed out, failed in its dispatch or was cancelled
+    by EngineStopped still records its queue wait; one refused at submit
+    (QueueFull) never queued and records none."""
+    X = np.zeros((2, D), np.float32)
+    t0 = obs.clock()
+    engine = ServeEngine(registry, EngineConfig(max_batch=32, max_queue=2),
+                         autostart=False)
+    doomed = engine.submit(X, timeout=0.01)
+    stranded = engine.submit(X)
+    with pytest.raises(QueueFull):
+        engine.submit(X)
+    time.sleep(0.05)
+    engine.stop()                                # cancels both
+    for fut in (doomed, stranded):
+        with pytest.raises(EngineStopped):
+            fut.result(5)
+    (queue,) = _spans(t0, "serve.queue")
+    assert len(queue) == 2
+    assert set(queue["parent"]) == {obs.NO_PARENT}
+
+    t0 = obs.clock()
+    engine = ServeEngine(registry, EngineConfig(max_batch=32),
+                         autostart=False)
+    doomed = engine.submit(X, timeout=0.01)
+    time.sleep(0.05)
+    engine.start()
+    with pytest.raises(RequestTimeout):
+        doomed.result(30)
+    engine.stop()
+    (queue,) = _spans(t0, "serve.queue")
+    assert len(queue) == 1
+    assert queue["end"][0] - queue["start"][0] >= 0.01
+    assert queue["parent"][0] == obs.NO_PARENT
+
+    reg = ModelRegistry(max_batch=32)
+    reg.add("bin", registry.get("bin").km)
+    t0 = obs.clock()
+    engine = ServeEngine(reg, EngineConfig(max_batch=32), autostart=False)
+    failed = engine.submit(X, model="bin")
+    reg.remove("bin")
+    engine.start()
+    with pytest.raises(KeyError):
+        failed.result(30)
+    engine.stop()
+    disp, queue = _spans(t0, "serve.dispatch", "serve.queue")
+    assert len(disp) == len(queue) == 1
+    assert queue["parent"][0] == disp["id"][0]
 
 
 def test_submit_validates_shape(registry):
