@@ -81,11 +81,13 @@ def _as_cols(v):
 
 
 def _pad_lanes(v, interpret: bool) -> jnp.ndarray:
-    """Pad the RHS column count to the 128-lane width on hardware (a k <=
-    128 block occupies the same MXU lanes as k = 1, so padded columns are
-    free); interpret mode keeps the exact k."""
+    """Pad a block of k > 1 right-hand sides to the 128-lane width on
+    hardware, where the kernels contract it on the MXU: up to 128 columns
+    cost the MXU passes of one. A single column (k = 1) is not padded: the
+    kernels contract it on the VPU, where padding would only add zero
+    columns (``repro.kernels.kmvp``). Interpret mode keeps the exact k."""
     k = v.shape[1]
-    return v if interpret else _pad_cols(v, _round_up(k, 128))
+    return v if interpret or k == 1 else _pad_cols(v, _round_up(k, 128))
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "sigma", "bn", "bm", "bd",

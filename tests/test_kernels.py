@@ -1,4 +1,6 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -177,9 +179,65 @@ def test_multirhs_adjoint(k, kind, impl):
     else:
         O = ops.kmvp_fwd_chunked(x, z, B, **kw)
         G = ops.kmvp_t_chunked(x, z, V, **kw)
-    lhs, rhs = float(jnp.sum(O * V)), float(jnp.sum(B * G))
+    # Paired in float64, so the gap is the kernels' own: an f32 sum of
+    # these 129·k cancelling terms rounds by about the tolerance itself.
+    lhs = float(np.sum(np.asarray(O, np.float64) * np.asarray(V)))
+    rhs = float(np.sum(np.asarray(B, np.float64) * np.asarray(G)))
     scale = max(1.0, abs(lhs), abs(rhs))
     assert abs(lhs - rhs) / scale < 1e-5, (lhs, rhs)
+
+
+# ------------------------------------------- single RHS: the VPU contraction
+# k = 1 contracts the gram tile on the VPU, k > 1 on the MXU. The extra
+# shape has several n- and m-blocks with padded tails, so the lane and
+# sublane partial sums carry across blocks.
+SINGLE_RHS_CASES = [(shape, {}) for shape in ODD_SHAPES] + [
+    ((40, 600, 130), dict(bn=16, bm=256, bd=128))]
+
+
+def _kernel_dots(fn, *args):
+    """How many dot_generals the Pallas kernels that ``fn`` calls hold."""
+    from repro.core.introspect import _subjaxprs
+
+    def count(jaxpr, in_kernel):
+        total = 0
+        for eqn in jaxpr.eqns:
+            total += in_kernel and eqn.primitive.name == "dot_general"
+            inner = in_kernel or eqn.primitive.name == "pallas_call"
+            total += sum(count(sub, inner) for sub in _subjaxprs(eqn.params))
+        return total
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr, False)
+
+
+@pytest.mark.parametrize("policy", ["fp32", "bf16"])
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("shape,tiles", SINGLE_RHS_CASES)
+def test_single_rhs_vpu_contraction(shape, tiles, kind, policy):
+    """k = 1 (1-D or one column) matches the dense oracle and column 0 of
+    the k = 2 MXU path on the same data; its kernels hold only the cross
+    term's dot, the k = 2 kernels the contraction's too."""
+    n, m, d = shape
+    x, z, B, V = _multi_data(n, m, d, 2, jnp.float32)
+    kw = dict(kind=kind, sigma=_sigma(d), policy=policy, **tiles)
+    comp = POLICY_COMPUTE.get(policy, jnp.float32)
+    G = np.asarray(ref.gram_ref(x, z, kind=kind, sigma=_sigma(d)))
+    mxu_fwd = ops.kmvp_fwd(x, z, B, **kw)[:, 0]
+    mxu_t = ops.kmvp_t(x, z, V, **kw)[:, 0]
+    for b, v in [(B[:, 0], V[:, 0]), (B[:, :1], V[:, :1])]:
+        o, g = ops.kmvp_fwd(x, z, b, **kw), ops.kmvp_t(x, z, v, **kw)
+        assert o.shape == (n,) + b.shape[1:] and g.shape == (m,) + v.shape[1:]
+        o, g = o.reshape(n), g.reshape(m)
+        assert_allclose_dtype(o, G @ np.asarray(B[:, 0]), comp)
+        assert_allclose_dtype(g, G.T @ np.asarray(V[:, 0]), comp)
+        assert_allclose_dtype(o, mxu_fwd, jnp.float32)
+        assert_allclose_dtype(g, mxu_t, jnp.float32)
+    fwd = functools.partial(ops.kmvp_fwd, **kw)
+    t = functools.partial(ops.kmvp_t, **kw)
+    assert _kernel_dots(fwd, x, z, B[:, 0]) == 1
+    assert _kernel_dots(t, x, z, V[:, :1]) == 1
+    assert _kernel_dots(fwd, x, z, B) == 2
+    assert _kernel_dots(t, x, z, V) == 2
 
 
 # ----------------------------------------------------- dtype-policy parity
